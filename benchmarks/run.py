@@ -26,6 +26,9 @@ def main() -> None:
         bench_scale,
         bench_serving,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = 0

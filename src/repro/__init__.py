@@ -1,9 +1,1 @@
-"""repro — RAGdb reproduction grown into a jax_pallas serving system.
-
-Importing the package installs JAX compatibility shims (see compat.py)
-so every module can target one JAX API surface regardless of the
-pinned release.
-"""
-from repro import compat as _compat
-
-_compat.install()
+"""repro — RAGdb reproduction grown into a jax_pallas serving system."""

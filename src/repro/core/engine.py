@@ -201,11 +201,10 @@ SCORING_PATHS = ("map", "gemm", "kernel")
 
 
 def _default_backend() -> str:
-    """The live jax backend name (monkeypatch point for tests)."""
-    try:
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — no devices at all → host semantics
-        return "cpu"
+    """The live jax backend name (monkeypatch point for tests).  A
+    backend that fails to initialize raises here: serving a broken chip
+    through the host path would hide it."""
+    return jax.default_backend()
 
 
 def resolve_scoring_path(

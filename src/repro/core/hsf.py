@@ -163,7 +163,7 @@ def numpy_reference(doc_vecs, doc_sigs, query_vec, query_sig, alpha, beta):
     """Pure-numpy oracle for tests (no jax involvement at all)."""
     # analysis: allow[unpinned-reduction] -- float64 test oracle; extra
     #   mantissa absorbs reduction-order error, tests allow an eps band
-    cos = doc_vecs.astype(np.float64) @ query_vec.astype(np.float64)
+    cos = np.asarray(doc_vecs, np.float64) @ query_vec.astype(np.float64)
     d = doc_sigs.view(np.uint32)
     q = query_sig.view(np.uint32)
     ind = np.all((d & q) == q, axis=-1).astype(np.float64)

@@ -715,9 +715,13 @@ def _shard_rows_from(base: IVFIndex, shard_of_cluster: np.ndarray,
 
 def _pin_stacks(mesh, dv_stack, ds_stack, gid_stack):
     """Commit the stacked blocks to the mesh (dim 0 = shard axis) — one
-    device_put each; a no-op when already resident with that sharding."""
+    device_put each; a no-op when already resident with that sharding.
+    Host stacks go shard by shard straight to their devices: staging a
+    whole [S, L, D] stack on one device first does not fit HBM at
+    deployment size (4 x 65,536 docs at D=4096 is an 8 GiB f32 stack)."""
     if mesh is None:
-        return dv_stack, ds_stack, gid_stack
+        return (jnp.asarray(dv_stack), jnp.asarray(ds_stack),
+                jnp.asarray(gid_stack))
     sh = jax.sharding.NamedSharding(mesh, P("shards"))
     return (jax.device_put(dv_stack, sh), jax.device_put(ds_stack, sh),
             jax.device_put(gid_stack, sh))
@@ -741,9 +745,7 @@ def _build_plane(base: IVFIndex, n_shards: int, shard_of_cluster: np.ndarray,
             ds[s, : rows.size] = dsn[rows]
             gid[s, : rows.size] = rows
     mesh = make_shard_mesh(n_shards)
-    dv_j, ds_j, gid_j = _pin_stacks(
-        mesh, jnp.asarray(dv), jnp.asarray(ds), jnp.asarray(gid)
-    )
+    dv_j, ds_j, gid_j = _pin_stacks(mesh, dv, ds, gid)
     return ShardedIVFIndex(
         base=base, n_shards=int(n_shards),
         shard_of_cluster=np.asarray(shard_of_cluster, np.int32),

@@ -29,22 +29,27 @@ Batched multi-query (`hsf_score_topk_pallas`) — the serving-plane hot
 loop.  The whole query batch is VMEM-resident; one grid step consumes a
 (block_docs × D) doc tile and a (block_docs × W) signature tile:
 
-    cos    = q_batch @ docsᵀ                      (MXU, [B,D]×[block,D])
-    ind    = containment, streamed word-by-word   (VPU, no [B,block,W]
-             over the W signature words            intermediate)
+    cos    = q_batch @ docsᵀ                      (MXU, [B,D]×[block,D],
+                                                   f32 accuracy)
+    ind    = containment as 32 bit-plane counts   (MXU, no [B,block,W]
+             |q ∧ d| == |q|                        intermediate)
     scores = α·cos + β·ind, padding masked to -inf
-    top-k  = k-pass argmax merge of (carry ‖ block scores) into a
-             [B, KPAD] running candidate set in VMEM scratch
+    top-k  = k passes of (max score, min id holding it) over the
+             [B, KPAD] carry and the block, written into a new carry
 
 The carry makes the kernel single-pass over HBM *and* keeps the full
 [B, N] score matrix from ever existing: only [B, k] survives each step.
 Tie-breaking is (score desc, doc-id asc), bit-identical to
-`retrieval._stable_top_k`: carried ids are always smaller than the
-current block's ids and both candidate lists are kept sorted, so
-argmax's first-match semantics implement the lexicographic rule for
-free.  Rows that never fill (k > n_valid) surface ID_SENTINEL with a
--inf score.  A scalar ``n_valid`` rides in SMEM so mesh-sharded callers
-can mask their local padding range without a second kernel variant.
+`retrieval._stable_top_k`: each pass takes the maximum score and, among
+its holders, the smallest id — no gather, no sortedness assumption.
+Rows that never fill (k > n_valid) surface ID_SENTINEL with a -inf
+score.  A scalar ``n_valid`` rides in SMEM so mesh-sharded callers can
+mask their local padding range without a second kernel variant.
+
+Compiled for v5e at the full retrieval config (N=65,536, D=4096, W=128,
+B=64, k=16, block 512, f32) the kernel's scoped VMEM is 17.7 MiB under a
+tight limit, so ``vmem_limit_bytes`` is set from the tile sizes
+(``_vmem_limit_bytes``); tests/test_tpu_compile.py keeps that compile.
 """
 from __future__ import annotations
 
@@ -126,8 +131,42 @@ KPAD = 128  # scratch lane width (same carry layout as kernels/topk)
 ID_SENTINEL = 2**31 - 1  # id of never-filled carry slots
 
 
+SIG_BITS = 32  # bits per int32 signature word
+_NT = (((1,), (1,)), ((), ()))  # dot_general: contract both minor axes
+
+
+def _containment(qs, sg):
+    """[B, block] bool: every set bit of query row b is set in doc row j.
+
+    Counted on the MXU one bit plane at a time: the 0/1 plane products
+    sum to |q_b ∧ d_j|, which equals |q_b| exactly when q_b ⊆ d_j.  The
+    planes are 0/1 in bf16 and the counts stay ≤ 32·W, so the f32
+    accumulation is exact.  No [B, block, W] intermediate and no lane
+    slicing; the loop keeps one plane of each operand live.
+    """
+    b, block = qs.shape[0], sg.shape[0]
+
+    def plane(t, carry):
+        hits, need = carry
+        qb = jax.lax.shift_right_logical(qs, t) & 1
+        db = jax.lax.shift_right_logical(sg, t) & 1
+        hits = hits + jax.lax.dot_general(
+            qb.astype(jnp.float32).astype(jnp.bfloat16),
+            db.astype(jnp.float32).astype(jnp.bfloat16),
+            _NT, preferred_element_type=jnp.float32,
+        )
+        need = need + jnp.sum(qb.astype(jnp.float32), axis=1, keepdims=True)
+        return hits, need
+
+    hits, need = jax.lax.fori_loop(
+        0, SIG_BITS, plane,
+        (jnp.zeros((b, block), jnp.float32), jnp.zeros((b, 1), jnp.float32)),
+    )
+    return hits == need
+
+
 def _hsf_topk_kernel(nvalid_ref, q_ref, qsig_ref, docs_ref, sigs_ref,
-                     vals_ref, ids_ref, vscr, iscr,
+                     vals_ref, ids_ref, vscr, iscr, bscr,
                      *, k, alpha, beta, block, nblocks):
     i = pl.program_id(0)
 
@@ -136,66 +175,82 @@ def _hsf_topk_kernel(nvalid_ref, q_ref, qsig_ref, docs_ref, sigs_ref,
         vscr[...] = jnp.full_like(vscr, NEG_INF)
         iscr[...] = jnp.full_like(iscr, jnp.int32(ID_SENTINEL))
 
+    q = q_ref[...]        # [B, D] f32
     docs = docs_ref[...]  # [block, D]
-    q = q_ref[...]        # [B, D]
     # MXU: [B, D] × [block, D] with D-contraction → [B, block], f32 acc.
+    # HIGHEST keeps f32 operands at f32 accuracy (the default may take
+    # a single bf16 pass); a bf16 doc tile is widened first.
     cos = jax.lax.dot_general(
-        q, docs, (((1,), (1,)), ((), ())),
+        q, docs.astype(jnp.float32), _NT,
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
-
-    # VPU: containment streamed over signature words.  The naive
-    # broadcast materializes [B, block, W] (int32 — megabytes of VMEM at
-    # serving batch sizes); folding word-by-word keeps the working set
-    # at one [B, block] boolean.
-    qs = qsig_ref[...]    # [B, W] int32
-    sg = sigs_ref[...]    # [block, W] int32
+    ok = _containment(qsig_ref[...], sigs_ref[...])
     b = q.shape[0]
-
-    def w_body(wi, ok):
-        qw = jax.lax.dynamic_slice(qs, (0, wi), (b, 1))      # [B, 1]
-        sw = jax.lax.dynamic_slice(sg, (0, wi), (block, 1))  # [block, 1]
-        return ok & ((sw.reshape(1, block) & qw) == qw)
-
-    ok = jax.lax.fori_loop(0, qs.shape[1], w_body,
-                           jnp.full((b, block), True))
-    scores = alpha * cos + beta * ok.astype(jnp.float32)
 
     # mask docs past n_valid (ragged-N padding, sharded-suffix padding)
     lids = i * block + jax.lax.broadcasted_iota(jnp.int32, (b, block), 1)
-    scores = jnp.where(lids < nvalid_ref[0], scores, NEG_INF)
+    bscr[...] = jnp.where(lids < nvalid_ref[0],
+                          alpha * cos + beta * ok.astype(jnp.float32),
+                          NEG_INF)
 
-    # merge carry ‖ block with k argmax passes.  First-match argmax is
-    # the (score desc, id asc) rule: the carry is sorted and holds only
-    # ids from earlier blocks (strictly smaller than any lid here), and
-    # within the block ids ascend with lane position.
-    cand_v = jnp.concatenate([vscr[...], scores], axis=1)  # [B, KPAD+block]
-    cand_i = jnp.concatenate([iscr[...], lids], axis=1)
-    new_v, new_i = [], []
-    for _ in range(k):  # k static — unrolled VPU reduction chain
-        a = jnp.argmax(cand_v, axis=1)  # [B]
-        new_v.append(jnp.take_along_axis(cand_v, a[:, None], axis=1))
-        new_i.append(jnp.take_along_axis(cand_i, a[:, None], axis=1))
-        knocked = (
-            jax.lax.broadcasted_iota(jnp.int32, cand_v.shape, 1)
-            == a[:, None]
+    # merge carry ∪ block with k passes of (max score, then min id among
+    # that score's holders) — the (score desc, id asc) rule without a
+    # gather.  Each pick is knocked out of the scratch it came from (ids
+    # are unique across carry and block), so the loop's live set is the
+    # two [B, KPAD] outputs.  A -inf pick means the row cannot fill: it
+    # surfaces ID_SENTINEL, never a masked or already-emitted doc id.
+    lane = jax.lax.broadcasted_iota(jnp.int32, (b, KPAD), 1)
+
+    def pick(j, out):
+        out_v, out_i = out
+        cv, ci, bv = vscr[...], iscr[...], bscr[...]
+        m = jnp.maximum(jnp.max(cv, axis=1, keepdims=True),
+                        jnp.max(bv, axis=1, keepdims=True))
+        sel = jnp.minimum(
+            jnp.min(jnp.where(cv == m, ci, ID_SENTINEL), axis=1,
+                    keepdims=True),
+            jnp.min(jnp.where(bv == m, lids, ID_SENTINEL), axis=1,
+                    keepdims=True),
         )
-        cand_v = jnp.where(knocked, NEG_INF, cand_v)
-        # clear the id too: once every candidate is -inf (k > n_valid),
-        # argmax re-picks slot 0 — without this, that slot still holds
-        # an already-emitted doc id and unfillable rows would surface
-        # duplicate real ids instead of the documented sentinel
-        cand_i = jnp.where(knocked, jnp.int32(ID_SENTINEL), cand_i)
-    pad = KPAD - k
-    vscr[...] = jnp.concatenate(
-        new_v + [jnp.full((b, pad), NEG_INF, vscr.dtype)], axis=1)
-    iscr[...] = jnp.concatenate(
-        new_i + [jnp.full((b, pad), jnp.int32(ID_SENTINEL))], axis=1)
+        vscr[...] = jnp.where(ci == sel, NEG_INF, cv)
+        bscr[...] = jnp.where(lids == sel, NEG_INF, bv)
+        sel = jnp.where(m == NEG_INF, ID_SENTINEL, sel)
+        return (jnp.where(lane == j, m, out_v),
+                jnp.where(lane == j, sel, out_i))
+
+    out_v, out_i = jax.lax.fori_loop(
+        0, k, pick,
+        (jnp.full((b, KPAD), NEG_INF, jnp.float32),
+         jnp.full((b, KPAD), ID_SENTINEL, jnp.int32)),
+    )
+    vscr[...] = out_v
+    iscr[...] = out_i
 
     @pl.when(i == nblocks - 1)
     def _final():
-        vals_ref[...] = vscr[...]
-        ids_ref[...] = iscr[...]
+        vals_ref[...] = out_v
+        ids_ref[...] = out_i
+
+
+def _vmem_limit_bytes(b, d, w, block, doc_itemsize):
+    """Scoped-VMEM limit for the kernel.
+
+    ``need`` counts the double-buffered doc, signature and query tiles,
+    the resident outputs and scratch, and the [B, block] temporaries of
+    the scoring epilogue.  The limit is twice that: given room, XLA also
+    places the call's small operands in VMEM, which the compiler charges
+    to the same scoped allocation (at B=256, block 512: 21.4 MiB under a
+    tight limit, 43.9 MiB under a loose one).  v5e has 128 MiB of VMEM;
+    the cap leaves a quarter of it to the surrounding XLA program.
+    """
+    tiles = (block * d * doc_itemsize + block * w * 4   # doc + sig tiles
+             + b * d * 4 + b * w * 4)                   # query tiles
+    carry = b * KPAD * (4 + 4)                          # values + ids
+    need = (2 * tiles + 2 * carry                       # double-buffered
+            + carry + b * block * 4                     # scratch
+            + b * block * 4 * 8 + block * w * 4 * 4)    # temporaries
+    return int(min(max(2 * need, 32 << 20), 96 << 20))
 
 
 @functools.partial(
@@ -247,9 +302,12 @@ def hsf_score_topk_pallas(
         scratch_shapes=[
             pltpu.VMEM((b, KPAD), jnp.float32),
             pltpu.VMEM((b, KPAD), jnp.int32),
+            pltpu.VMEM((b, block_docs), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_vmem_limit_bytes(
+                b, d, w, block_docs, doc_vecs.dtype.itemsize),
         ),
         interpret=interpret,
         name="hsf_topk_batched",

@@ -24,6 +24,7 @@ import jax
 from repro.configs import get as get_arch
 from repro.core.ingest import KnowledgeBase
 from repro.core.rag import RAGPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.obs import (
     SLOTargets,
@@ -123,6 +124,7 @@ def main(argv=None):
                     "(runtime.health(): ok | degraded | critical with "
                     "reasons) after the run")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.trace:
         obs_trace.enable()

@@ -293,6 +293,41 @@ def test_sharded_mesh_matches_logical_fallback():
     assert out1 == out4 and out1.strip()
 
 
+def test_sharded_plane_places_each_block_on_its_own_device():
+    """Building the mesh plane sends each shard's resident block from
+    the host straight to its device: the whole [S, L, D] stack is never
+    staged on one device (at 4 x 65,536 docs, D=4096 that stack is
+    8 GiB and does not fit beside the doc matrix in one chip's HBM)."""
+    run_with_devices("""
+        import jax, jax.numpy as jnp
+        from repro.core.engine import QueryEngine
+        from repro.core.ingest import KnowledgeBase
+        from repro.data.corpus import make_corpus
+
+        staged = []
+        asarray = jnp.asarray
+        def spy(x, *a, **kw):
+            out = asarray(x, *a, **kw)
+            if getattr(out, "ndim", 0) == 3:
+                staged.append(out.shape)
+            return out
+        jnp.asarray = spy
+
+        docs, _ = make_corpus(n_docs=61, n_entities=4, seed=7)
+        kb = KnowledgeBase(dim=512)
+        for i, d in enumerate(docs):
+            kb.add_text(f"doc_{i:05d}.txt", d)
+        eng = QueryEngine(kb, scoring_path="map", index="ivf-sharded",
+                          guarantee="exact", n_shards=4)
+        assert staged == [], staged
+        shards = eng.ivf.dv_stack.addressable_shards
+        assert len({s.device for s in shards}) == 4
+        assert all(s.data.shape == (1,) + eng.ivf.dv_stack.shape[1:]
+                   for s in shards)
+        print("OK")
+    """, n_devices=4)
+
+
 # --------------------------------------------------------------------------
 # serving runtime: sharded index under live sync, pinned generations
 # --------------------------------------------------------------------------
