@@ -267,26 +267,29 @@ def score_batch_arrays(
         return empty_f, empty_i, empty_f.copy(), empty_f.copy()
     with obs_trace.span("device_dispatch", path=scoring_path,
                         rows=int(n_docs), k=k):
-        if scoring_path == "kernel":
-            if kernel_operands is None:
-                kernel_operands = hsf.hsf_kernel_pad_docs(doc_vecs, doc_sigs)
-            dv, ds = kernel_operands
-            vals, idx, cos, ind = _score_topk_pallas(
-                dv, ds, jnp.asarray(qv), jnp.asarray(qs), jnp.int32(n_docs),
-                k=k, alpha=alpha, beta=beta,
-            )
-        else:
-            vals, idx, cos, ind = _score_topk(
-                doc_vecs, doc_sigs, jnp.asarray(qv), jnp.asarray(qs),
-                jnp.int32(n_docs),
-                k=k, alpha=alpha, beta=beta, gemm=scoring_path == "gemm",
-            )
+        if scoring_path == "kernel" and kernel_operands is None:
+            kernel_operands = hsf.hsf_kernel_pad_docs(doc_vecs, doc_sigs)
+        with obs_trace.span("query_upload", bytes=qv.nbytes + qs.nbytes):
+            qv_dev, qs_dev = jax.device_put((qv, qs))
+        with obs_trace.span("launch"):
+            if scoring_path == "kernel":
+                dv, ds = kernel_operands
+                vals, idx, cos, ind = _score_topk_pallas(
+                    dv, ds, qv_dev, qs_dev, jnp.int32(n_docs),
+                    k=k, alpha=alpha, beta=beta,
+                )
+            else:
+                vals, idx, cos, ind = _score_topk(
+                    doc_vecs, doc_sigs, qv_dev, qs_dev, jnp.int32(n_docs),
+                    k=k, alpha=alpha, beta=beta, gemm=scoring_path == "gemm",
+                )
         if obs_trace.active():
             # tracing/explain-only audited sync: without it the async
             # dispatch returns immediately and all device time would be
             # charged to the host_transfer span below.  Never runs when
             # neither a trace nor an EXPLAIN collector is active.
-            jax.block_until_ready(vals)  # analysis: allow[host-sync] -- tracing/explain-only audited boundary attributing device time to the dispatch span; no-op when both are off
+            with obs_trace.span("device_wait"):
+                jax.block_until_ready(vals)  # analysis: allow[host-sync] -- tracing/explain-only audited boundary attributing device time to the dispatch span; no-op when both are off
     with obs_trace.span("host_transfer", k=k):
         return (np.asarray(vals), np.asarray(idx),
                 np.asarray(cos), np.asarray(ind))
@@ -362,6 +365,18 @@ def _record_ivf_stats(s) -> None:
         reg.histogram("ragdb_ivf_merge_seconds",
                       "sharded local-top-k merge per dispatch").record(
             float(merge_s))
+
+
+def _upload(host: np.ndarray, what: str):
+    """Send a host block of the doc planes to the device under an
+    ``upload`` span (``what``: ``vecs``, ``sigs`` or ``row_patch``).
+    With tracing or EXPLAIN on, the span waits for the copy to land, so
+    its time is the transfer's and not the enqueue's."""
+    with obs_trace.span("upload", bytes=host.nbytes, what=what):
+        dev = jnp.asarray(host)
+        if obs_trace.active():
+            jax.block_until_ready(dev)  # analysis: allow[host-sync] -- tracing/explain-only audited boundary attributing the host-to-device copy to the upload span; no-op when both are off
+    return dev
 
 
 def _pad_row_update(rows: np.ndarray, block: np.ndarray):
@@ -548,19 +563,20 @@ class QueryEngine:
             matrix, sigs, ids = kb.materialize()
             self._u = None
             self._idf = kb.vectorizer.idf()
-            self.doc_vecs = jnp.asarray(matrix)
-            self.doc_sigs = jnp.asarray(sigs)
+            self.doc_vecs = _upload(matrix, "vecs")
+            self.doc_sigs = _upload(sigs, "sigs")
         else:
             ids = sorted(kb.records)
             tcs = [kb.term_counts[i] for i in ids]
             self._u = kb.vectorizer.build_unweighted_matrix(tcs)
             self._idf = kb.vectorizer.idf()
-            self.doc_vecs = jnp.asarray(kb.vectorizer.finalize_matrix(self._u))
-            self.doc_sigs = jnp.asarray(
+            with obs_trace.span("reweight", rows=len(ids)):
+                matrix = kb.vectorizer.finalize_matrix(self._u)
+            self.doc_vecs = _upload(matrix, "vecs")
+            self.doc_sigs = _upload(
                 np.stack([kb.signatures[i] for i in ids])
                 if ids
-                else np.zeros((0, kb.sig_words), np.int32)
-            )
+                else np.zeros((0, kb.sig_words), np.int32), "sigs")
         self.doc_ids = ids
         self._row_of = {i: r for r, i in enumerate(ids)}
 
@@ -607,7 +623,7 @@ class QueryEngine:
                 sig_block = np.stack([kb.signatures[i] for i in changed])
                 rows_p, sig_p = _pad_row_update(rows, sig_block)
                 self.doc_sigs = self.doc_sigs.at[rows_p].set(
-                    jnp.asarray(sig_p)
+                    _upload(sig_p, "row_patch")
                 )
         else:
             # layout changed: restack cached rows on the host (pure
@@ -624,7 +640,7 @@ class QueryEngine:
                     u[r] = self._u[old_r]
                     sig[r] = old_sig[old_r]
             self._u = u
-            self.doc_sigs = jnp.asarray(sig)
+            self.doc_sigs = _upload(sig, "sigs")
             self.doc_ids = new_ids
             self._row_of = {i: r for r, i in enumerate(new_ids)}
             stats.restacked = True
@@ -634,7 +650,9 @@ class QueryEngine:
             # idf moved: the cheap global stage — elementwise reweight +
             # renormalize of the cached U, nothing re-vectorized
             self._idf = idf
-            self.doc_vecs = jnp.asarray(kb.vectorizer.finalize_matrix(self._u))
+            with obs_trace.span("reweight", rows=len(self._u)):
+                matrix = kb.vectorizer.finalize_matrix(self._u)
+            self.doc_vecs = _upload(matrix, "vecs")
             stats.reweighted = True
             self._qcache.clear()  # query vectors depend on idf
         elif changed:
@@ -642,7 +660,8 @@ class QueryEngine:
             rows = np.array([self._row_of[i] for i in changed], np.int32)
             block = kb.vectorizer.finalize_matrix(self._u[rows])
             rows_p, block_p = _pad_row_update(rows, block)
-            self.doc_vecs = self.doc_vecs.at[rows_p].set(jnp.asarray(block_p))
+            self.doc_vecs = self.doc_vecs.at[rows_p].set(
+                _upload(block_p, "row_patch"))
             stats.rows_patched = len(rows)
 
     # ---- index plane maintenance (index="ivf") --------------------------
@@ -793,21 +812,46 @@ class QueryEngine:
     # ---- query-vector cache --------------------------------------------
 
     def _query_arrays(self, text: str) -> tuple[np.ndarray, np.ndarray]:
-        key = normalize(text)
-        hit = self._qcache.get(key)
-        if hit is not None:
-            self._qcache.move_to_end(key)
-            self.cache_hits += 1
-            return hit
-        self.cache_misses += 1
-        out = (
-            self.kb.vectorizer.query_vector(text),
-            sigmod.query_signature(text, width_words=self.kb.sig_words),
-        )
-        self._qcache[key] = out
-        if len(self._qcache) > self.cache_size:
-            self._qcache.popitem(last=False)
-        return out
+        return self._query_pairs([text])[0]
+
+    def _query_pairs(self, texts: list[str]) -> list[tuple]:
+        """(vector, signature) per query, through the query-vector LRU.
+
+        Two passes, each under its own span: every missing vector
+        (``query_vector``), then every missing signature
+        (``query_signature``).  The LRU bookkeeping then replays the
+        per-query lookup in request order (hits, misses, recency,
+        eviction), so the counts and the cache end as a query-by-query
+        pass leaves them."""
+        cache = self._qcache
+        keys = [normalize(t) for t in texts]
+        known: dict[str, tuple | None] = {}
+        todo: list[tuple[str, str]] = []
+        for key, t in zip(keys, texts):
+            if key not in known:
+                known[key] = cache.get(key)
+                if known[key] is None:
+                    todo.append((key, t))
+        with obs_trace.span("query_vector", queries=len(todo)):
+            vecs = [self.kb.vectorizer.query_vector(t) for _, t in todo]
+        with obs_trace.span("query_signature", queries=len(todo)):
+            sigs = [sigmod.query_signature(t, width_words=self.kb.sig_words)
+                    for _, t in todo]
+        for (key, _), v, sg in zip(todo, vecs, sigs):
+            known[key] = (v, sg)
+        pairs = []
+        for key in keys:
+            hit = cache.get(key)
+            if hit is not None:
+                cache.move_to_end(key)
+                self.cache_hits += 1
+            else:
+                self.cache_misses += 1
+                hit = cache[key] = known[key]
+                if len(cache) > self.cache_size:
+                    cache.popitem(last=False)
+            pairs.append(hit)
+        return pairs
 
     # ---- batched queries ------------------------------------------------
 
@@ -874,9 +918,10 @@ class QueryEngine:
             scope = _NULL_CTX
         with scope:
             with obs_trace.span("query_embed", queries=b):
-                pairs = [self._query_arrays(t) for t in texts]
-                qv, qs = pack_query_arrays(
-                    pairs, self.kb.dim, self.kb.sig_words)
+                pairs = self._query_pairs(texts)
+                with obs_trace.span("query_pack", queries=b):
+                    qv, qs = pack_query_arrays(
+                        pairs, self.kb.dim, self.kb.sig_words)
             n = len(self.doc_ids)
             stats = None
             if self.index != "flat" and self.ivf is not None:

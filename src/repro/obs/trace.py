@@ -29,11 +29,17 @@ explicit across threads: the scheduler allocates a trace id at submit
 time on the caller's thread and the flusher thread records that
 request's stage spans against it via ``record(..., trace=tid)``.
 
+Garbage collection: the default tracer hooks ``gc.callbacks`` while it
+is enabled (and only then), recording one ``gc`` span per collection as
+a trace of its own with args ``generation`` and ``collected``.  The
+pause stops every thread, so the span names it wherever it lands.
+
 Env knobs: ``RAGDB_TRACE=1`` enables the default tracer at import;
 ``RAGDB_TRACE_SAMPLE=0.01`` sets its sampling rate.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import os
 import threading
@@ -200,7 +206,7 @@ class Tracer:
     """See module docstring.  One instance = one ring buffer."""
 
     def __init__(self, capacity: int = DEFAULT_CAPACITY,
-                 sample: float = 1.0):
+                 sample: float = 1.0, *, gc_spans: bool = False):
         self._buf: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
         self._tls = threading.local()
@@ -210,6 +216,10 @@ class Tracer:
         self._ids = itertools.count(1)
         self._trace_n = itertools.count()
         self._period = 1
+        # one bound method, so enable/disable add and remove the same
+        # object from gc.callbacks
+        self._gc_hook = self._on_gc if gc_spans else None
+        self._gc_t0 = 0
         self.configure(sample=sample)
 
     # ---- lifecycle ------------------------------------------------------
@@ -228,11 +238,15 @@ class Tracer:
     def enable(self, *, sample: float | None = None,
                capacity: int | None = None) -> "Tracer":
         self.configure(sample=sample, capacity=capacity)
+        if self._gc_hook is not None and self._gc_hook not in gc.callbacks:
+            gc.callbacks.append(self._gc_hook)
         self._enabled = True
         return self
 
     def disable(self) -> None:
         self._enabled = False
+        if self._gc_hook is not None and self._gc_hook in gc.callbacks:
+            gc.callbacks.remove(self._gc_hook)
 
     @property
     def enabled(self) -> bool:
@@ -358,6 +372,24 @@ class Tracer:
                 tid, args if args is not None else {},
             ))
 
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks`` hook: one ``gc`` span per collection, a trace
+        of its own (no parent, no sampling slot taken).  Collections do
+        not nest, so one start time per tracer suffices."""
+        if phase == "start":
+            self._gc_t0 = time.perf_counter_ns()
+            return
+        if not self._enabled or not self._gc_t0:
+            return
+        t0, self._gc_t0 = self._gc_t0, 0
+        ids = self._ids
+        self._buf.append((
+            "gc", next(ids), next(ids), 0, t0, time.perf_counter_ns() - t0,
+            threading.get_ident(),
+            {"generation": info.get("generation"),
+             "collected": info.get("collected")},
+        ))
+
     # ---- buffer access --------------------------------------------------
 
     def spans(self) -> list[SpanRecord]:
@@ -396,7 +428,7 @@ class Tracer:
 
 # ---- module-level default tracer (what the instrumentation uses) --------
 
-_DEFAULT = Tracer()
+_DEFAULT = Tracer(gc_spans=True)
 
 
 def get() -> Tracer:

@@ -332,6 +332,9 @@ class MicroBatchScheduler:
     # ---- the flusher ----------------------------------------------------
 
     def _worker(self) -> None:
+        # start of the current wait for a first request: the flusher's
+        # idle time plus batch formation, recorded as ``batch_wait``
+        t_wait = time.perf_counter()
         while True:
             try:
                 first = self._queue.get(timeout=0.1)
@@ -344,6 +347,7 @@ class MicroBatchScheduler:
             first.t_dequeue = time.perf_counter()
             batch = [first]
             deadline = first.t_dequeue + self.flush_deadline
+            stop = False
             while len(batch) < self.max_batch:
                 remaining = deadline - time.perf_counter()
                 if remaining <= 0:
@@ -353,11 +357,20 @@ class MicroBatchScheduler:
                 except queue.Empty:
                     break
                 if item is _STOP:
-                    self._flush(batch)
-                    return
+                    stop = True
+                    break
                 item.t_dequeue = time.perf_counter()
                 batch.append(item)
+            if first.trace_id:
+                # on the opening request's trace, like the flush span:
+                # no begin_trace, so no sampling slot is spent on it
+                trace.record("batch_wait", t_wait,
+                             time.perf_counter() - t_wait,
+                             trace=first.trace_id, batch=len(batch))
             self._flush(batch)
+            if stop:
+                return
+            t_wait = time.perf_counter()
 
     def _flush(self, batch: list[_Pending]) -> None:
         # the flush-level span (and the engine/index spans nesting under
@@ -393,8 +406,12 @@ class MicroBatchScheduler:
                         req.future.set_exception(exc)
             finally:
                 self.metrics.on_batch(len(batch), scored)
-        for args in deferred:
-            self._trace_request(*args)
+        if deferred:
+            # tracing's own cost, paid after every future has resolved
+            with trace.span("trace_emit", trace=flush_trace,
+                            requests=len(deferred)):
+                for args in deferred:
+                    self._trace_request(*args)
 
     def _flush_tenant(self, tenant: str, group: list[_Pending],
                       deferred: list[tuple]) -> int:
@@ -455,37 +472,38 @@ class MicroBatchScheduler:
                     for req in kgroup:
                         key = normalize(req.text)
                         fanout[key] = fanout.get(key, 0) + 1
-                for req in kgroup:
-                    key = normalize(req.text)
-                    res = results[order[key]]
-                    if self.cache is not None:
-                        self.cache.put(req.text, k, snap.generation,
-                                       res, keyspace=tenant)
-                    t_done = time.perf_counter()
-                    self.metrics.on_complete(t_done - req.t_submit,
-                                             mt_tenant)
-                    plan_source = None
-                    if req.explain and qplans is not None:
-                        # enrich the engine plan with the scheduler
-                        # view: the same timestamps _trace_request
-                        # records, so EXPLAIN stage durations tile the
-                        # span decomposition by construction
-                        plan_source = _plan_thunk(
-                            qplans, order[key], mt_tenant,
-                            snap.generation,
-                            ("miss" if self.cache is not None
-                             else "bypass"),
-                            fanout[key], req.t_submit, req.t_dequeue,
-                            t_score0, t_score1, t_done)
-                    req.future.set_result(
-                        ServedResult(res, snap.generation,
-                                     plan_source=plan_source)
-                    )
-                    if req.trace_id:
-                        deferred.append(
-                            (req, k, snap.generation,
-                             t_score0, t_score1, t_done, len(texts),
-                             mt_tenant))
+                with trace.span("fanout", requests=len(kgroup)):
+                    for req in kgroup:
+                        key = normalize(req.text)
+                        res = results[order[key]]
+                        if self.cache is not None:
+                            self.cache.put(req.text, k, snap.generation,
+                                           res, keyspace=tenant)
+                        t_done = time.perf_counter()
+                        self.metrics.on_complete(t_done - req.t_submit,
+                                                 mt_tenant)
+                        plan_source = None
+                        if req.explain and qplans is not None:
+                            # enrich the engine plan with the scheduler
+                            # view: the same timestamps _trace_request
+                            # records, so EXPLAIN stage durations tile
+                            # the span decomposition by construction
+                            plan_source = _plan_thunk(
+                                qplans, order[key], mt_tenant,
+                                snap.generation,
+                                ("miss" if self.cache is not None
+                                 else "bypass"),
+                                fanout[key], req.t_submit, req.t_dequeue,
+                                t_score0, t_score1, t_done)
+                        req.future.set_result(
+                            ServedResult(res, snap.generation,
+                                         plan_source=plan_source)
+                        )
+                        if req.trace_id:
+                            deferred.append(
+                                (req, k, snap.generation,
+                                 t_score0, t_score1, t_done, len(texts),
+                                 mt_tenant))
         except Exception as exc:  # noqa: BLE001 — fail this tenant's group only
             for req in group:
                 if not req.future.done():

@@ -40,6 +40,7 @@ from repro.core import signature as sigmod
 from repro.core.engine import (
     QueryEngine,
     RetrievalResult,
+    _record_ivf_stats,
     pack_query_arrays,
     results_from_topk,
     score_batch_arrays,
@@ -162,15 +163,15 @@ class EngineSnapshot:
             scope = _NULL_CTX
         with scope:
             with obs_trace.span("query_embed", queries=len(texts)):
-                pairs = [
-                    (
-                        self.vectorizer.query_vector(t),
-                        sigmod.query_signature(t, width_words=self.sig_words),
-                    )
-                    for t in texts
-                ]
-                qv, qs = pack_query_arrays(
-                    pairs, self.vectorizer.dim, self.sig_words)
+                with obs_trace.span("query_vector", queries=len(texts)):
+                    vecs = [self.vectorizer.query_vector(t) for t in texts]
+                with obs_trace.span("query_signature", queries=len(texts)):
+                    sigs = [sigmod.query_signature(
+                        t, width_words=self.sig_words) for t in texts]
+                with obs_trace.span("query_pack", queries=len(texts)):
+                    qv, qs = pack_query_arrays(
+                        list(zip(vecs, sigs)), self.vectorizer.dim,
+                        self.sig_words)
             n = len(self.doc_ids)
             stats = None
             if self.index_kind != "flat" and self.ivf is not None:
@@ -180,6 +181,7 @@ class EngineSnapshot:
                     guarantee=self.guarantee, scoring_path=self.scoring_path,
                     alpha=self.alpha, beta=self.beta, explain=explain,
                 )
+                _record_ivf_stats(stats)
             else:
                 vals, idx, cos, ind = score_batch_arrays(
                     self.doc_vecs, self.doc_sigs, qv, qs,
