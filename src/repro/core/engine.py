@@ -32,8 +32,13 @@ the single-query `Retriever` could not give a multi-user deployment:
    (vectorizer.py) is what makes this exact: per-doc ``u_d`` rows are
    cached, and the global idf reweight is a cheap elementwise pass —
    the same O(U) split the paper uses for ingest (§3.3), applied to the
-   query plane.  The refreshed arrays are bit-identical to a cold
-   ``materialize()`` rebuild.
+   query plane.  Where the reweight runs follows the scoring path: the
+   map/gemm paths run the host ``finalize_matrix`` and the refreshed
+   arrays are bit-identical to a cold ``materialize()`` rebuild; the
+   kernel path keeps the ``u`` rows resident on the device, patches the
+   changed ones and reweights there (one jitted pass, no [N, D] upload),
+   bit-identical to a cold build on the kernel path and within float32
+   rounding of ``materialize()``.
 
 3. **Query-vector LRU cache** — keyed on the canonicalized query text
    (tokenizer.normalize), invalidated only when the idf statistics
@@ -183,9 +188,33 @@ def _score_topk_pallas(doc_vecs, doc_sigs, q_vecs, q_sigs, n_valid,
     return vals, idx, cos, ind
 
 
+# rows per device u-row patch: one compiled scatter shape serves any delta
+_U_PATCH_ROWS = 64
+
+
+@partial(jax.jit, donate_argnums=(0,))
+def _patch_u_rows(u, rows, block):
+    """Write ``block`` [_U_PATCH_ROWS, D] into rows ``rows`` of the
+    device u cache.  ``u`` is donated: only the engine holds it."""
+    return u.at[rows].set(block)
+
+
+@jax.jit
+def _reweight_rows(u, idf):
+    """Device twin of ``HashedTfIdf.finalize_matrix``: ``u ⊙ idf`` with
+    each row ℓ2-normalized, zero rows left zero.  Float32 throughout;
+    the result is a new buffer, since published snapshots pin the old
+    doc matrix."""
+    v = u * idf[None, :]
+    norm = jnp.sqrt(jnp.sum(v * v, axis=1, keepdims=True))
+    return jnp.where(norm > 0, v / jnp.where(norm > 0, norm, 1.0), 0.0)
+
+
 # steady-state retrace accounting (no-op unless RAGDB_SANITIZERS is on)
 sanitizers.register_jit("engine._score_topk", _score_topk)
 sanitizers.register_jit("engine._score_topk_pallas", _score_topk_pallas)
+sanitizers.register_jit("engine._patch_u_rows", _patch_u_rows)
+sanitizers.register_jit("engine._reweight_rows", _reweight_rows)
 
 
 def _bucket(b: int) -> int:
@@ -369,7 +398,8 @@ def _record_ivf_stats(s) -> None:
 
 def _upload(host: np.ndarray, what: str):
     """Send a host block of the doc planes to the device under an
-    ``upload`` span (``what``: ``vecs``, ``sigs`` or ``row_patch``).
+    ``upload`` span (``what``: ``vecs``, ``sigs``, ``row_patch``, or on
+    the kernel path ``u``, ``u_patch`` and ``idf``).
     With tracing or EXPLAIN on, the span waits for the copy to land, so
     its time is the transfer's and not the enqueue's."""
     with obs_trace.span("upload", bytes=host.nbytes, what=what):
@@ -501,6 +531,8 @@ class QueryEngine:
         self.doc_sigs = jnp.zeros((0, kb.sig_words), jnp.int32)
         self._row_of: dict[str, int] = {}
         self._u = np.zeros((0, kb.dim), np.float32)  # cached tf·sign rows
+        # kernel path: device copy of ``_u``, the reweight's operand
+        self._u_dev = None
         self._idf = np.zeros((0,), np.float32)
         self._synced = -1  # KB version the device arrays reflect
 
@@ -561,7 +593,7 @@ class QueryEngine:
             # — that skip is the whole point of persisting ⟨V⟩ (RQ3).
             # The u-row cache is built lazily on the first delta.
             matrix, sigs, ids = kb.materialize()
-            self._u = None
+            self._u = self._u_dev = None
             self._idf = kb.vectorizer.idf()
             self.doc_vecs = _upload(matrix, "vecs")
             self.doc_sigs = _upload(sigs, "sigs")
@@ -570,15 +602,54 @@ class QueryEngine:
             tcs = [kb.term_counts[i] for i in ids]
             self._u = kb.vectorizer.build_unweighted_matrix(tcs)
             self._idf = kb.vectorizer.idf()
-            with obs_trace.span("reweight", rows=len(ids)):
-                matrix = kb.vectorizer.finalize_matrix(self._u)
-            self.doc_vecs = _upload(matrix, "vecs")
+            self._reweight()
             self.doc_sigs = _upload(
                 np.stack([kb.signatures[i] for i in ids])
                 if ids
                 else np.zeros((0, kb.sig_words), np.int32), "sigs")
         self.doc_ids = ids
         self._row_of = {i: r for r, i in enumerate(ids)}
+
+    def _patch_u(self, rows: np.ndarray) -> None:
+        """Copy host u rows ``rows`` into the device u, in chunks of
+        ``_U_PATCH_ROWS`` (the last padded by repeating its first row:
+        writing identical content twice is deterministic)."""
+        for s in range(0, len(rows), _U_PATCH_ROWS):
+            chunk = rows[s: s + _U_PATCH_ROWS]
+            chunk = np.concatenate(
+                [chunk, np.repeat(chunk[:1], _U_PATCH_ROWS - len(chunk))])
+            self._u_dev = _patch_u_rows(
+                self._u_dev, chunk, _upload(self._u[chunk], "u_patch"))
+
+    def _reweight(self) -> None:
+        """The global stage: idf reweight + row ℓ2-normalize of every
+        cached u row into a new doc matrix.  The kernel path runs it on
+        the device from the resident u; map/gemm run the host
+        ``finalize_matrix``, whose bits ``kb.materialize()`` shares."""
+        on = "device" if self.use_kernel else "host"
+        global_registry().counter(
+            "ragdb_reweight_total", "doc-matrix reweights by where they ran",
+            on=on).inc()
+        if on == "host":
+            with obs_trace.span("reweight", rows=len(self._u), on=on):
+                matrix = self.kb.vectorizer.finalize_matrix(self._u)
+            self.doc_vecs = _upload(matrix, "vecs")
+            return
+        if self._u_dev is None:  # cold build, restack, adopted matrix
+            self._u_dev = _upload(self._u, "u")
+            if len(self._u):
+                # compile the row patch now (row 0 rewritten with
+                # itself), so that no later in-place delta compiles
+                self._patch_u(np.zeros((1,), np.int32))
+        idf = _upload(self._idf, "idf")
+        with obs_trace.span("reweight", rows=len(self._u), on=on):
+            self.doc_vecs = _reweight_rows(self._u_dev, idf)
+            # the operand cache holds the previous matrix: let it go now,
+            # before the next capture pads this one
+            self._kernel_cache = None
+            # a publish returns a finished matrix: one reweight in flight
+            # at most, however fast the writer publishes
+            jax.block_until_ready(self.doc_vecs)  # analysis: allow[host-sync] -- publish-path backpressure on the kernel path's device reweight (writer thread, never the query path); bounds in-flight reweights and their [N, D] buffers to one
 
     def _ensure_u(self) -> None:
         """Materialize the u-row cache for the engine's current layout.
@@ -601,7 +672,12 @@ class QueryEngine:
 
     def _apply_delta(self, changed: list[str], stats: RefreshStats) -> None:
         kb = self.kb
-        if not changed and sorted(kb.records) == self.doc_ids:
+        # the doc-id set is unchanged iff nothing was added (every added
+        # id is in ``changed``) and the count still matches (nothing
+        # removed) — O(U), no pass over the N ids
+        same_layout = (len(kb.records) == len(self.doc_ids)
+                       and all(i in self._row_of for i in changed))
+        if not changed and same_layout:
             # metadata-only mutation (e.g. the KB re-armed stat fast-path
             # keys on a touched-but-unchanged file): no rows to patch and
             # df cannot have moved — skip the u-cache materialization
@@ -612,8 +688,7 @@ class QueryEngine:
             i: kb.vectorizer.unweighted_row(kb.term_counts[i])
             for i in changed
         }
-        new_ids = sorted(kb.records)
-        if new_ids == self.doc_ids:
+        if same_layout:
             if changed:
                 rows = np.array(
                     [self._row_of[i] for i in changed], np.int32
@@ -625,9 +700,12 @@ class QueryEngine:
                 self.doc_sigs = self.doc_sigs.at[rows_p].set(
                     _upload(sig_p, "row_patch")
                 )
+                if self._u_dev is not None:
+                    self._patch_u(rows)
         else:
             # layout changed: restack cached rows on the host (pure
             # memcpy for unchanged docs — no re-vectorization)
+            new_ids = sorted(kb.records)
             u = np.zeros((len(new_ids), kb.dim), np.float32)
             sig = np.zeros((len(new_ids), kb.sig_words), np.int32)
             old_sig = np.asarray(self.doc_sigs)
@@ -640,6 +718,7 @@ class QueryEngine:
                     u[r] = self._u[old_r]
                     sig[r] = old_sig[old_r]
             self._u = u
+            self._u_dev = None  # the reweight below uploads it whole
             self.doc_sigs = _upload(sig, "sigs")
             self.doc_ids = new_ids
             self._row_of = {i: r for r, i in enumerate(new_ids)}
@@ -650,18 +729,21 @@ class QueryEngine:
             # idf moved: the cheap global stage — elementwise reweight +
             # renormalize of the cached U, nothing re-vectorized
             self._idf = idf
-            with obs_trace.span("reweight", rows=len(self._u)):
-                matrix = kb.vectorizer.finalize_matrix(self._u)
-            self.doc_vecs = _upload(matrix, "vecs")
+            self._reweight()
             stats.reweighted = True
             self._qcache.clear()  # query vectors depend on idf
         elif changed:
-            # idf stable: patch only the dirty rows on device
+            # idf stable: only the dirty rows change
             rows = np.array([self._row_of[i] for i in changed], np.int32)
-            block = kb.vectorizer.finalize_matrix(self._u[rows])
-            rows_p, block_p = _pad_row_update(rows, block)
-            self.doc_vecs = self.doc_vecs.at[rows_p].set(
-                _upload(block_p, "row_patch"))
+            if self.use_kernel:
+                # the device pass over the patched u: a row block would
+                # round its norms apart from the cold build's full pass
+                self._reweight()
+            else:
+                block = kb.vectorizer.finalize_matrix(self._u[rows])
+                rows_p, block_p = _pad_row_update(rows, block)
+                self.doc_vecs = self.doc_vecs.at[rows_p].set(
+                    _upload(block_p, "row_patch"))
             stats.rows_patched = len(rows)
 
     # ---- index plane maintenance (index="ivf") --------------------------

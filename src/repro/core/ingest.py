@@ -174,6 +174,19 @@ def extract(data: bytes, path: str = "") -> tuple[str, str]:
 # knowledge base (in-memory state behind a container)
 # --------------------------------------------------------------------------
 
+def _logged_since(log: dict[str, int], version: int) -> list[str]:
+    """Sorted ids of a version-ordered change log (``id -> version``,
+    insertion order = version order) logged strictly after ``version``;
+    reads only those newest entries."""
+    out = []
+    for path, v in reversed(log.items()):
+        if v <= version:
+            break
+        out.append(path)
+    out.sort()
+    return out
+
+
 @dataclass
 class IngestStats:
     scanned: int = 0
@@ -211,6 +224,9 @@ class KnowledgeBase:
     vectorizer: HashedTfIdf = None
     records: dict[str, DocRecord] = field(default_factory=dict)
     texts: dict[str, str] = field(default_factory=dict)
+    # sum of len(text) over ``texts``, kept by _put_text/_pop_text so the
+    # resource ledger's per-publish container estimate reads no text
+    _text_bytes: int = 0
     term_counts: dict[str, TermCounts] = field(default_factory=dict)
     signatures: dict[str, np.ndarray] = field(default_factory=dict)
     _dirty: bool = True
@@ -313,12 +329,15 @@ class KnowledgeBase:
         self.vectorizer.add_doc(tc)
         self.records[path] = DocRecord(path, digest, kind, mtime, size,
                                        mtime_ns)
-        self.texts[path] = text
+        self._put_text(path, text)
         self.term_counts[path] = tc
         self.signatures[path] = sigmod.signature_of_text(
             text, width_words=self.sig_words
         )
         self._version += 1
+        # re-inserted, not updated in place: the log stays in version
+        # order, so ``changes_since`` reads only its newest entries
+        self._changed_at.pop(path, None)
         self._changed_at[path] = self._version
         self._removed_at.pop(path, None)
         self._meta_changed_at.pop(path, None)  # superseded by full change
@@ -332,10 +351,17 @@ class KnowledgeBase:
     # this many deletions behind.
     REMOVED_LOG_MAX = 4096
 
+    def _put_text(self, path: str, text: str) -> None:
+        self._text_bytes += len(text) - len(self.texts.get(path, ""))
+        self.texts[path] = text
+
+    def _pop_text(self, path: str) -> None:
+        self._text_bytes -= len(self.texts.pop(path, ""))
+
     def _remove_doc(self, path: str):
         self.vectorizer.remove_doc(self.term_counts.pop(path))
         self.records.pop(path)
-        self.texts.pop(path)
+        self._pop_text(path)
         self.signatures.pop(path)
         self._version += 1
         self._changed_at.pop(path, None)
@@ -391,13 +417,8 @@ class KnowledgeBase:
         consumers must derive authoritative removals from the current
         ``records`` key set, as core/engine.py does.
         """
-        changed = sorted(
-            p for p, v in self._changed_at.items() if v > version
-        )
-        removed = sorted(
-            p for p, v in self._removed_at.items() if v > version
-        )
-        return changed, removed
+        return (_logged_since(self._changed_at, version),
+                _logged_since(self._removed_at, version))
 
     # ---- the paper's incremental sync ----------------------------------
 
@@ -843,7 +864,7 @@ class KnowledgeBase:
         for j, d in enumerate(docs_meta):
             i = d["id"]
             self.records[i] = self._record_from_meta(d)
-            self.texts[i] = texts[j]
+            self._put_text(i, texts[j])
             self.term_counts[i] = TermCounts(
                 segs["term_hashes"][ptr[j]: ptr[j + 1]],
                 segs["term_counts"][ptr[j]: ptr[j + 1]],
@@ -859,7 +880,7 @@ class KnowledgeBase:
         0), exactly like a KB loaded from the equivalent full save."""
         for rid in meta.get("removed", []):
             self.records.pop(rid, None)
-            self.texts.pop(rid, None)
+            self._pop_text(rid)
             self.term_counts.pop(rid, None)
             self.signatures.pop(rid, None)
         self._restore_doc_rows(meta["docs"], segs)

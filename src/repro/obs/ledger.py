@@ -8,6 +8,8 @@ resident, broken down by *plane*:
                      members, and the sharded resident blocks)
 - ``kernel_operands`` block-aligned padded doc operands for the fused
                      kernel path
+- ``u_rows``         the kernel path's device copy of the unweighted
+                     rows, the operand of its on-device reweight
 - ``result_cache``   per-generation result-cache entries (host)
 - ``container``      the host-side KnowledgeBase (records, texts,
                      signatures) — an estimate, documented below
@@ -37,7 +39,7 @@ import threading
 
 # planes that occupy accelerator/host *memory* for scoring — what the
 # pool's resident budget constrains
-DEVICE_PLANES = ("doc_matrix", "ivf_state", "kernel_operands")
+DEVICE_PLANES = ("doc_matrix", "ivf_state", "kernel_operands", "u_rows")
 # memory-resident planes (everything but the on-disk journal tail)
 RESIDENT_PLANES = DEVICE_PLANES + ("result_cache", "container")
 ALL_PLANES = RESIDENT_PLANES + ("journal_tail",)
@@ -156,12 +158,15 @@ def measure_engine_planes(engine) -> dict:
     cache = getattr(engine, "_kernel_cache", None)
     planes["kernel_operands"] = (
         _nbytes(cache[2]) + _nbytes(cache[3]) if cache else 0)
+    planes["u_rows"] = _nbytes(getattr(engine, "_u_dev", None))
     kb = engine.kb
-    # host container estimate: per-doc signatures are exact; text +
+    # host container estimate: per-doc signatures are exact (fixed-width
+    # int32 rows of ``sig_words``), text bytes are the KB's running sum;
     # per-record metadata (id, sha, term counts) approximated at
-    # 256 B/record
-    est = sum(_nbytes(s) for s in getattr(kb, "signatures", {}).values())
-    est += sum(len(t) for t in getattr(kb, "texts", {}).values())
+    # 256 B/record.  Nothing here visits a document: this runs on every
+    # publish
+    est = 4 * getattr(kb, "sig_words", 0) * len(getattr(kb, "signatures", {}))
+    est += getattr(kb, "_text_bytes", 0)
     est += 256 * len(getattr(kb, "records", {}))
     planes["container"] = est
     return planes

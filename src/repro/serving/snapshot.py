@@ -6,7 +6,8 @@ builds the next one and publishes it with one atomic reference swap.
 Applied to the query plane:
 
 - ``EngineSnapshot`` freezes everything a query needs at generation
-  *g*: the device-resident doc matrix + signature matrix (jnp arrays
+  *g*: the device-resident doc matrix + signature matrix, or on the
+  flat kernel path only their block-aligned kernel operands (jnp arrays
   are immutable — ``refresh()`` only ever *rebinds* the engine's
   attributes, so a captured array can never be half-updated), the doc
   id layout, and a **copy** of the vectorizer's idf state (df array +
@@ -60,7 +61,9 @@ class EngineSnapshot:
 
     generation: int
     doc_ids: tuple[str, ...]
-    doc_vecs: object          # jnp [N, D] — immutable device array
+    doc_vecs: object          # jnp [N, D] — immutable device array;
+    #                           None on the flat kernel path, which
+    #                           scores from ``kernel_operands`` alone
     doc_sigs: object          # jnp [N, W]
     vectorizer: HashedTfIdf   # private copy: df frozen at `generation`
     sig_words: int
@@ -88,19 +91,22 @@ class EngineSnapshot:
         thread) must have run ``engine.refresh()`` first so the arrays
         reflect ``engine.synced_version == kb.version``."""
         vec = engine.kb.vectorizer
+        operands = engine._kernel_operands() if engine.use_kernel else None
         return EngineSnapshot(
             generation=engine.synced_version,
             doc_ids=tuple(engine.doc_ids),
-            doc_vecs=engine.doc_vecs,
+            # the flat kernel scan reads only the block-aligned copy:
+            # pinning the unaligned matrix as well would hold a second
+            # [N, D] buffer for every generation a reader still holds
+            doc_vecs=(None if operands is not None and engine.index == "flat"
+                      else engine.doc_vecs),
             doc_sigs=engine.doc_sigs,
             vectorizer=HashedTfIdf.from_state(vec.state(), vec.df.copy()),
             sig_words=engine.kb.sig_words,
             alpha=engine.alpha,
             beta=engine.beta,
             scoring_path=engine.scoring_path,
-            kernel_operands=(
-                engine._kernel_operands() if engine.use_kernel else None
-            ),
+            kernel_operands=operands,
             max_batch=engine.max_batch,
             index_kind=engine.index,
             ivf=engine.ivf,

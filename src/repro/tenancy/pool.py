@@ -88,7 +88,7 @@ class MountedTenant:
     @property
     def resident_bytes(self) -> int:
         """Device footprint per the resource ledger (doc matrix + IVF
-        state + kernel operands, re-measured at mount and every
+        state + kernel operands + u rows, re-measured at mount and every
         publish) — the *same* accounting ``ServingRuntime.resources()``
         reports, so budget decisions and reported occupancy can never
         diverge.  Falls back to a raw array-nbytes estimate when no

@@ -256,6 +256,109 @@ def test_queries_see_kb_mutations_automatically():
 
 
 # --------------------------------------------------------------------------
+# kernel path: the reweight runs on the device from resident u rows
+# --------------------------------------------------------------------------
+
+def _assert_kernel_matches_cold(engine, kb):
+    """Bit-identical to a cold build on the kernel path, and within
+    float32 rounding of the host ``kb.materialize()``."""
+    cold = QueryEngine(kb, scoring_path="kernel")
+    assert engine.doc_ids == cold.doc_ids
+    got = np.asarray(engine.doc_vecs)
+    np.testing.assert_array_equal(got, np.asarray(cold.doc_vecs))
+    np.testing.assert_array_equal(np.asarray(engine.doc_sigs),
+                                  np.asarray(cold.doc_sigs))
+    np.testing.assert_array_equal(np.asarray(engine._u_dev), engine._u)
+    matrix, sigs, ids = kb.materialize()
+    assert engine.doc_ids == ids
+    assert np.abs(got - matrix).max() <= 1e-6
+    np.testing.assert_array_equal(np.asarray(engine.doc_sigs), sigs)
+
+
+def test_device_reweight_matches_host_finalize():
+    from repro.core.vectorizer import HashedTfIdf
+
+    rng = np.random.default_rng(7)
+    u = rng.normal(size=(37, 512)).astype(np.float32)
+    u[[0, 5, 36]] = 0.0
+    idf = rng.uniform(0.5, 6.0, size=512).astype(np.float32)
+    got = np.asarray(engine_mod._reweight_rows(u, idf))
+    want = HashedTfIdf(dim=512).finalize_matrix(u.copy(), idf)
+    assert got.dtype == np.float32
+    assert np.abs(got - want).max() <= 1e-6
+    assert not got[[0, 5, 36]].any()  # zero rows stay zero
+    np.testing.assert_allclose(
+        np.linalg.norm(np.delete(got, [0, 5, 36], axis=0), axis=1), 1.0,
+        rtol=1e-6)
+
+
+def test_kernel_path_refresh_add_update_remove_equals_cold():
+    kb, _ = _kb(n_docs=50)
+    engine = QueryEngine(kb, scoring_path="kernel")
+    _assert_kernel_matches_cold(engine, kb)
+
+    kb.add_text("zz_new_doc", "a brand new document QQ-1111")   # add
+    assert engine.refresh().restacked
+    _assert_kernel_matches_cold(engine, kb)
+
+    kb.add_text("doc_00007.txt", "doc seven rewritten RR-2222")  # update
+    stats = engine.refresh()
+    assert not stats.restacked and stats.reweighted
+    _assert_kernel_matches_cold(engine, kb)
+
+    text = kb.texts["doc_00011.txt"]                # idf-stable update:
+    kb.add_text("doc_00011.txt", text + " " + text.split()[0])  # same terms
+    stats = engine.refresh()
+    assert not stats.reweighted and stats.rows_patched == 1
+    _assert_kernel_matches_cold(engine, kb)
+
+    kb._remove_doc("doc_00003.txt")                              # remove
+    assert engine.refresh().restacked
+    _assert_kernel_matches_cold(engine, kb)
+    assert engine.refresh().no_op
+
+
+def test_kernel_path_delta_larger_than_a_patch_chunk():
+    n = engine_mod._U_PATCH_ROWS + 9
+    kb, _ = _kb(n_docs=n + 5, dim=512)
+    engine = QueryEngine(kb, scoring_path="kernel")
+    for i in range(n):
+        kb.add_text(f"doc_{i:05d}.txt", f"rewritten passage {i} XY-{i:04d}")
+    stats = engine.refresh()
+    assert stats.changed == n and not stats.restacked
+    _assert_kernel_matches_cold(engine, kb)
+
+
+def test_kernel_path_after_adopting_persisted_matrix(tmp_path):
+    kb, _ = _kb(n_docs=30)
+    path = str(tmp_path / "kb.ragdb")
+    kb.save(path, include_matrix=True)
+    kb2 = KnowledgeBase.load(path)
+    engine = QueryEngine(kb2, scoring_path="kernel")
+    assert engine._u_dev is None  # persisted matrix adopted, u deferred
+    kb2.add_text("doc_00002.txt", "rewritten after load WW-7777")
+    engine.refresh()  # u uploads whole on the first delta
+    _assert_kernel_matches_cold(engine, kb2)
+
+
+@pytest.mark.parametrize("n_changed", [1, 3, 64, 65])
+def test_kernel_path_publish_compiles_nothing(n_changed):
+    """After the engine build, an in-place delta of any size reuses the
+    one compiled u patch and the one compiled [N, D] reweight."""
+    from repro.analysis.sanitizers import RetraceGuard
+
+    kb, _ = _kb(n_docs=70, dim=512)
+    engine = QueryEngine(kb, scoring_path="kernel")
+    guard = RetraceGuard()
+    guard.arm()
+    for i in range(n_changed):
+        kb.add_text(f"doc_{i:05d}.txt", f"fresh text {i} ZQ-{i:04d}")
+    assert engine.refresh().changed == n_changed
+    assert guard.report() == {}
+    _assert_kernel_matches_cold(engine, kb)
+
+
+# --------------------------------------------------------------------------
 # query-vector LRU cache
 # --------------------------------------------------------------------------
 

@@ -230,3 +230,49 @@ def test_container_roundtrip_preserves_everything(tmp_path):
     # and incremental sync continues to work post-restore
     s = kb2.sync(src)
     assert s.processed == 0 and s.skipped == 2
+
+
+def test_changes_since_after_rewrites_and_removals():
+    """The change log reads only its newest entries, so it must stay in
+    version order when a doc changes again or comes back after removal."""
+    kb = KnowledgeBase(dim=256)
+    for name in ("a", "b", "c", "d"):
+        kb.add_text(name, f"text of {name}")
+    v0 = kb.version
+    kb.add_text("b", "b rewritten")
+    v1 = kb.version
+    kb.add_text("c", "c rewritten")
+    kb._remove_doc("d")
+    v2 = kb.version
+    kb.add_text("b", "b rewritten again")
+    kb.add_text("d", "d is back")
+    assert kb.changes_since(v0) == (["b", "c", "d"], [])
+    assert kb.changes_since(v1) == (["b", "c", "d"], [])
+    assert kb.changes_since(v2) == (["b", "d"], [])
+    assert kb.changes_since(kb.version) == ([], [])
+    kb._remove_doc("a")
+    assert kb.changes_since(v2) == (["b", "d"], ["a"])
+    assert kb.changes_since(0) == (["b", "c", "d"], ["a"])
+
+
+def test_text_bytes_tracks_texts_through_changes_and_reload(tmp_path):
+    """The running text-byte sum the resource ledger reads matches the
+    texts after adds, rewrites, removals, a load and a journal replay."""
+    def check(kb):
+        assert kb._text_bytes == sum(len(t) for t in kb.texts.values())
+
+    kb = KnowledgeBase(dim=256)
+    for name in ("a", "b", "c"):
+        kb.add_text(name, f"text of {name} " * 3)
+    check(kb)
+    kb.add_text("b", "b is shorter")
+    kb._remove_doc("c")
+    check(kb)
+    path = str(tmp_path / "kb.ragdb")
+    kb.save(path)
+    kb.add_text("a", "a rewritten after the save, and longer than before")
+    kb._remove_doc("b")
+    kb.add_text("d", "a new doc")
+    kb.save_delta(path, compact_ratio=None)
+    check(kb)
+    check(KnowledgeBase.load(path))

@@ -222,6 +222,27 @@ def test_publish_that_moves_idf_reweights_and_uploads_the_matrix(tracer):
     assert by_id[by_id[rw.parent_id].parent_id].name == "publish"
 
 
+def test_kernel_path_publish_reweights_on_the_device(tracer):
+    kb = _kb(n_docs=640)
+    mgr = SnapshotManager(kb, scoring_path="kernel")
+    total = global_registry().counter("ragdb_reweight_total", on="device")
+    before = total.value
+    kb.add_text("doc_003.txt", "alpha beta entity INV-0003 novelterm")
+    spans = _publish_spans(tracer, mgr)
+    by_id = {s.span_id: s for s in spans}
+    (rw,) = [s for s in spans if s.name == "reweight"]
+    assert rw.args["on"] == "device"
+    assert by_id[rw.parent_id].name == "refresh"
+    ups = [s for s in spans if s.name == "upload"]
+    assert {s.args["what"] for s in ups} == {"u_patch", "row_patch", "idf"}
+    sent = sum(s.args["bytes"] for s in ups)
+    # one padded u chunk, one signature row and idf: no [N, D] matrix
+    assert sent == (64 + 1) * DIM * 4 + kb.sig_words * 4
+    assert sent * 8 < mgr.engine.doc_vecs.nbytes
+    assert all(by_id[s.parent_id].name == "refresh" for s in ups)
+    assert total.value == before + 1
+
+
 def test_publish_with_stable_idf_patches_rows(tracer):
     kb = _kb()
     mgr = SnapshotManager(kb, scoring_path="map")

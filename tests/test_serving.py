@@ -132,6 +132,26 @@ def test_snapshot_pins_generation_across_mutations():
     assert top.doc_id == "torn_doc" and top.boosted
 
 
+def test_kernel_path_snapshot_pins_only_the_aligned_operands():
+    """On the flat kernel path a snapshot scores from the block-aligned
+    operands alone, so it does not pin the unaligned matrix too, and it
+    still serves its own generation after the next publish."""
+    kb, entities = _kb(n_docs=25)
+    queries = [next(iter(entities)), "TORN-1111"]
+    manager = SnapshotManager(kb, scoring_path="kernel")
+    snap0 = manager.current
+    assert snap0.doc_vecs is None and snap0.kernel_operands is not None
+    before = snap0.query_batch(queries, k=3)
+    assert_bit_identical(before, manager.engine.query_batch(queries, k=3))
+
+    kb.add_text("torn_doc", "fresh document about TORN-1111 exactly")
+    snap1 = manager.publish()
+    assert snap1.doc_vecs is None
+    assert_bit_identical(before, snap0.query_batch(queries, k=3))
+    top = snap1.query_batch(["TORN-1111"], k=1)[0][0]
+    assert top.doc_id == "torn_doc" and top.boosted
+
+
 def test_snapshot_matches_engine_frozen_at_same_generation():
     """A snapshot's query vectors come from its own idf copy: results
     equal a direct engine on a KB frozen at that generation, even after

@@ -112,3 +112,22 @@ def test_sharded_local_topk_compiles_on_four_chips(topo):
     ).compile()
     per_device = _fits_hbm(compiled)
     assert per_device > rows * FULL.dim * 4  # one f32 resident block
+
+
+def test_device_reweight_compiles(one_chip):
+    """The kernel path's publish programs at the benchmark's one-chip
+    DPR share (82,092 rows): the u-row patch, which donates ``u``, and
+    the [N, D] reweight into a new doc matrix."""
+    from repro.core.engine import _U_PATCH_ROWS, _patch_u_rows, _reweight_rows
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    n = 82_092
+    u = s((n, FULL.dim), jnp.float32)
+    patch = _patch_u_rows.lower(
+        u, s((_U_PATCH_ROWS,), jnp.int32),
+        s((_U_PATCH_ROWS, FULL.dim), jnp.float32)).compile()
+    assert patch.memory_analysis().alias_size_in_bytes >= n * FULL.dim * 4
+    reweight = _reweight_rows.lower(u, s((FULL.dim,), jnp.float32)).compile()
+    assert _fits_hbm(reweight) >= 2 * n * FULL.dim * 4
