@@ -1007,8 +1007,11 @@ class QueryEngine:
             n = len(self.doc_ids)
             stats = None
             if self.index != "flat" and self.ivf is not None:
+                # the kernel path's index reads the block-aligned pair
+                docs = (self._kernel_operands() if self.use_kernel
+                        else (self.doc_vecs, self.doc_sigs))
                 vals, idx, cos, ind, stats = self.ivf.search(
-                    self.doc_vecs, self.doc_sigs, qv, qs,
+                    *docs, qv, qs,
                     b=b, k=min(k, n), nprobe=self.nprobe,
                     guarantee=self.guarantee,
                     scoring_path=self.scoring_path,

@@ -52,6 +52,7 @@ from __future__ import annotations
 import hashlib
 import time
 from dataclasses import dataclass, replace
+from functools import cached_property
 
 import jax
 import jax.numpy as jnp
@@ -262,6 +263,12 @@ class IVFIndex:
     def n_clusters(self) -> int:
         return self.centroids.shape[0]
 
+    @cached_property
+    def _centroids_t64(self) -> np.ndarray:
+        """[D, kc] float64 centroids for the probe product, converted
+        once per index rather than on every dispatch."""
+        return np.ascontiguousarray(self.centroids.T, np.float64)
+
     @property
     def n_docs(self) -> int:
         return len(self.assign)
@@ -358,6 +365,9 @@ class IVFIndex:
         probing, but all padded rows are scored (their output is
         ignored by ``results_from_topk``).  ``explain=True``
         additionally materializes per-query probe tuples on the stats.
+        On the kernel path ``doc_vecs``/``doc_sigs`` are the block-aligned
+        pair (``hsf_kernel_pad_docs``): the flat collapse scores it with
+        no pad per dispatch and the gather reads its rows below N.
         """
         n, kc = self.n_docs, self.n_clusters
         kk = min(k, n)
@@ -367,10 +377,8 @@ class IVFIndex:
         # -- probe plane (host, float64 for the exactness bound) ----------
         # analysis: allow[unpinned-reduction] -- f64 probe bound, clipped
         #   to [-1,1]; prunes candidates only, exact rerank follows
-        a = np.clip(
-            qv[:b].astype(np.float64) @ self.centroids.T.astype(np.float64),
-            -1.0, 1.0,
-        )                                                   # [b, kc]
+        a = np.clip(qv[:b].astype(np.float64) @ self._centroids_t64,
+                    -1.0, 1.0)                              # [b, kc]
         qsig = qs[:b].astype(np.int32)
         contain = np.all(
             (self.sig_union[None, :, :] & qsig[:, None, :])
@@ -462,6 +470,46 @@ class IVFIndex:
         )
         return vals, idx, cos, ind, stats
 
+    def warm(self, doc_vecs, doc_sigs, *, k: int, max_batch: int,
+             guarantee: str, scoring_path: str, alpha: float,
+             beta: float) -> None:
+        """Compile every shape ``search`` can dispatch at ``k`` for
+        flushes of up to ``max_batch`` queries: the flat collapse at each
+        power-of-two query bucket, and the gather and the scoring of each
+        candidate-row bucket at each gathered query bucket.  Exact mode
+        gathers a batch's union only below half the rows; probe mode
+        gathers one query at a time, anything short of the whole corpus.
+        ``doc_vecs``/``doc_sigs`` are the pair ``search`` is given.
+        """
+        n = self.n_docs
+        kk = min(k, n)
+        if not n:
+            return
+        dim, words = doc_vecs.shape[1], doc_sigs.shape[1]
+        exact = guarantee == "exact"
+        top = n // 2 if exact else n - 1     # most rows a gather holds
+        flat = [_bucket(b) for b in range(1, max_batch + 1)] if exact \
+            else [1]
+        for b in sorted(set(flat)):
+            score_batch_arrays(
+                doc_vecs, doc_sigs, np.zeros((b, dim), np.float32),
+                np.zeros((b, words), np.int32), scoring_path=scoring_path,
+                k=kk, alpha=alpha, beta=beta, n_docs=n)
+        if top < kk:
+            return                           # every search collapses
+        gathered = sorted({query_bucket(b, scoring_path) for b in flat})
+        rows = row_bucket(kk, n, scoring_path)
+        while True:
+            cand = np.arange(min(rows, top), dtype=np.int32)
+            for b in gathered:
+                score_candidate_rows(
+                    doc_vecs, doc_sigs, cand, np.zeros((b, dim), np.float32),
+                    np.zeros((b, words), np.int32),
+                    scoring_path=scoring_path, k=kk, alpha=alpha, beta=beta)
+            if rows >= row_bucket(top, n, scoring_path):
+                break
+            rows = row_bucket(rows + 1, n, scoring_path)
+
     def _search_exact(self, doc_vecs, doc_sigs, qv, qs, *, b, kk, p,
                       order, ub, scoring_path, alpha, beta,
                       explain=False):
@@ -505,7 +553,10 @@ class IVFIndex:
                     "ivf_widen_round", _tr, time.perf_counter() - _tr,
                     round=rounds,
                     rows=n if cand is None else int(cand.size),
-                    clusters=kc if cand is None else int(probed.size))
+                    clusters=kc if cand is None else int(probed.size),
+                    collapsed=cand is None,
+                    bucket=n if cand is None
+                    else row_bucket(cand.size, n, scoring_path))
             if cand is None:
                 break
             # stop test: the k-th best exact score must strictly beat
@@ -551,12 +602,44 @@ class IVFIndex:
 # candidate-gather scoring (shared by IVF rerank + the postings prefilter)
 # --------------------------------------------------------------------------
 
+# on the kernel path a candidate block is padded to a power of two of at
+# least ROW_FLOOR rows (so it is scored in the same 512-row kernel blocks
+# as the flat scan) and its query block to 8 or 64 rows (the kernel pads
+# a query block to 8 rows anyway): few enough shapes that a served engine
+# compiles them all before it takes traffic (``IVFIndex.warm``), where
+# each is seconds of Pallas lowering.  Map and gemm keep the plain
+# power-of-two buckets, whose compiles are cheap and whose work grows
+# with every padded row.
+ROW_FLOOR, QUERY_FLOOR = 1024, 8
+
+
+def row_bucket(n: int, n_docs: int, scoring_path: str) -> int:
+    """Rows a gather of ``n`` of ``n_docs`` candidates is padded to,
+    never more than the power of two at or above ``n_docs``."""
+    floor = ROW_FLOOR if scoring_path == "kernel" else 1
+    return min(max(floor, _bucket(n)), _bucket(max(n_docs, n)))
+
+
+def query_bucket(b: int, scoring_path: str) -> int:
+    """Query rows a gathered block of a ``b``-row query block is scored
+    with: a power of 8 from ``QUERY_FLOOR`` on the kernel path."""
+    if scoring_path != "kernel":
+        return b
+    q = QUERY_FLOOR
+    while q < b:
+        q *= 8
+    return q
+
+
 @jax.jit
 def _gather_rows(doc_vecs, doc_sigs, cand):
     """One fused dispatch for the two row gathers (eager jnp.take pays
-    per-op dispatch overhead twice on the per-query hot path)."""
-    return (jnp.take(doc_vecs, cand, axis=0),
-            jnp.take(doc_sigs, cand, axis=0))
+    per-op dispatch overhead twice on the per-query hot path).  Every
+    index is a row of the corpus (the pad repeats row 0), so ``clip``:
+    the default ``fill`` mode masks each gathered block in a second
+    full pass over it."""
+    return (jnp.take(doc_vecs, cand, axis=0, mode="clip"),
+            jnp.take(doc_sigs, cand, axis=0, mode="clip"))
 
 
 # steady-state retrace accounting (no-op unless RAGDB_SANITIZERS is on);
@@ -574,21 +657,34 @@ def score_candidate_rows(doc_vecs, doc_sigs, cand_rows: np.ndarray,
     ``cand_rows`` must be ascending global row indices — gathered-row
     order then equals global order, so ``lax.top_k``'s tie-breaking
     matches the flat scan, and the returned ``idx`` are mapped back to
-    *global* rows.  The subset is padded to a power-of-two row bucket
-    (bounded jit recompiles, same trick as the query batch) and scored
-    through ``score_batch_arrays`` with ``n_docs`` masking the pad —
-    the identical machinery (map / gemm / fused Pallas kernel) the flat
+    *global* rows.  The subset is padded to a ``row_bucket`` and the
+    query block to a ``query_bucket`` (bounded jit shapes, which
+    ``IVFIndex.warm`` compiles ahead) and scored through
+    ``score_batch_arrays`` with ``n_docs`` masking the pad — the
+    identical machinery (map / gemm / fused Pallas kernel) the flat
     paths dispatch, which is what makes subset scores bit-identical to
-    the corresponding rows of the full scan.
+    the corresponding rows of the full scan.  ``doc_vecs`` may carry
+    rows past the corpus (the kernel path's block-aligned copy): only
+    rows named in ``cand_rows`` are read.  Returns one row per row of
+    ``qv``.
     """
     n = int(len(cand_rows))
     kk = min(k, n)
-    candp = np.zeros((_bucket(n),), np.int32)
+    rows = row_bucket(n, doc_vecs.shape[0], scoring_path)
+    candp = np.zeros((rows,), np.int32)
     candp[:n] = cand_rows
-    sub_vecs, sub_sigs = _gather_rows(doc_vecs, doc_sigs,
-                                      jnp.asarray(candp))
+    bq = qv.shape[0]
+    qb = query_bucket(bq, scoring_path)
+    if qb > bq:
+        qv = np.concatenate([qv, np.zeros((qb - bq, qv.shape[1]), qv.dtype)])
+        qs = np.concatenate([qs, np.zeros((qb - bq, qs.shape[1]), qs.dtype)])
+    with obs_trace.span("ivf_gather", rows=rows, bytes=rows * (
+            doc_vecs.dtype.itemsize * doc_vecs.shape[1]
+            + doc_sigs.dtype.itemsize * doc_sigs.shape[1])):
+        sub_vecs, sub_sigs = _gather_rows(doc_vecs, doc_sigs,
+                                          jnp.asarray(candp))
     vals, idx, cos, ind = score_batch_arrays(
         sub_vecs, sub_sigs, qv, qs, scoring_path=scoring_path, k=kk,
         alpha=alpha, beta=beta, n_docs=n,
     )
-    return vals, candp[idx], cos, ind
+    return vals[:bq], candp[idx[:bq]], cos[:bq], ind[:bq]

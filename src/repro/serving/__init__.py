@@ -263,7 +263,9 @@ class ServingRuntime:
         that caused it (when ``RAGDB_SANITIZERS`` is on).
 
         Warming covers the power-of-two buckets {1, 2, 4, ..,
-        max_batch} at the given ``k`` against the *current* snapshot —
+        max_batch} at the given ``k`` against the *current* snapshot,
+        and on an IVF engine every candidate-row bucket its exact or
+        probe search can gather (``EngineSnapshot.warm``) —
         in multi-tenant mode, against every tenant in ``tenants``
         (default: the resident set), since each tenant's doc-array
         shapes trace their own jit entries; this is the per-tenant
@@ -275,18 +277,10 @@ class ServingRuntime:
                 else self.pool.resident_tenants()
             for name in names:
                 with self.pool.pinned(name) as mount:
-                    self._warm_buckets(mount.snapshots.current, k)
+                    mount.snapshots.current.warm(k, self.scheduler.max_batch)
         else:
-            self._warm_buckets(self.snapshots.current, k)
+            self.snapshots.current.warm(k, self.scheduler.max_batch)
         self.retrace_guard.arm()
-
-    def _warm_buckets(self, snap, k: int) -> None:
-        b = 1
-        while True:
-            snap.query_batch(["warmup bucket probe"] * b, k)
-            if b >= self.scheduler.max_batch:
-                break
-            b *= 2
 
     # ---- introspection ---------------------------------------------------
 

@@ -62,8 +62,9 @@ class EngineSnapshot:
     generation: int
     doc_ids: tuple[str, ...]
     doc_vecs: object          # jnp [N, D] — immutable device array;
-    #                           None on the flat kernel path, which
-    #                           scores from ``kernel_operands`` alone
+    #                           None on the kernel path, whose flat
+    #                           scan and IVF gather read
+    #                           ``kernel_operands`` alone
     doc_sigs: object          # jnp [N, W]
     vectorizer: HashedTfIdf   # private copy: df frozen at `generation`
     sig_words: int
@@ -95,11 +96,11 @@ class EngineSnapshot:
         return EngineSnapshot(
             generation=engine.synced_version,
             doc_ids=tuple(engine.doc_ids),
-            # the flat kernel scan reads only the block-aligned copy:
-            # pinning the unaligned matrix as well would hold a second
-            # [N, D] buffer for every generation a reader still holds
-            doc_vecs=(None if operands is not None and engine.index == "flat"
-                      else engine.doc_vecs),
+            # the kernel path (flat scan, IVF collapse and gather) reads
+            # only the block-aligned copy: pinning the unaligned matrix
+            # as well would hold a second [N, D] buffer for every
+            # generation a reader still holds
+            doc_vecs=None if operands is not None else engine.doc_vecs,
             doc_sigs=engine.doc_sigs,
             vectorizer=HashedTfIdf.from_state(vec.state(), vec.df.copy()),
             sig_words=engine.kb.sig_words,
@@ -117,6 +118,12 @@ class EngineSnapshot:
     @property
     def n_docs(self) -> int:
         return len(self.doc_ids)
+
+    @property
+    def index_docs(self) -> tuple:
+        """The doc pair the clustered index reads: the block-aligned
+        kernel operands on the kernel path, else the plain matrix."""
+        return self.kernel_operands or (self.doc_vecs, self.doc_sigs)
 
     def query_batch(
         self, texts: list[str], k: int = 5, *, explain: bool = False
@@ -159,6 +166,24 @@ class EngineSnapshot:
             return out, PlanBatch.concat(batches)
         return out
 
+    def warm(self, k: int, max_batch: int) -> None:
+        """Run every shape a flush of up to ``max_batch`` queries can
+        dispatch at ``k``: each power-of-two query bucket end to end,
+        and on a clustered index every candidate bucket its search can
+        gather (``IVFIndex.warm``), which queries alone would not reach."""
+        b = 1
+        while True:
+            self.query_batch(["warmup bucket probe"] * b, k)
+            if b >= max_batch:
+                break
+            b *= 2
+        if self.index_kind == "ivf" and self.ivf is not None and self.doc_ids:
+            self.ivf.warm(
+                *self.index_docs, k=min(k, self.n_docs),
+                max_batch=max_batch, guarantee=self.guarantee,
+                scoring_path=self.scoring_path, alpha=self.alpha,
+                beta=self.beta)
+
     def _chunk(self, texts: list[str], k: int, *, explain: bool = False):
         if explain:
             from repro.obs import explain as explain_mod
@@ -182,7 +207,7 @@ class EngineSnapshot:
             stats = None
             if self.index_kind != "flat" and self.ivf is not None:
                 vals, idx, cos, ind, stats = self.ivf.search(
-                    self.doc_vecs, self.doc_sigs, qv, qs,
+                    *self.index_docs, qv, qs,
                     b=len(texts), k=min(k, n), nprobe=self.nprobe,
                     guarantee=self.guarantee, scoring_path=self.scoring_path,
                     alpha=self.alpha, beta=self.beta, explain=explain,

@@ -66,6 +66,8 @@ def _fits_hbm(compiled) -> int:
     (FULL.docs_per_device, FULL.query_batch),  # flat scan of one chip
     (2048, FULL.query_batch),                  # IVF candidate bucket
     (64, 1),                                   # small bucket, probe mode
+    (1024, 8),                                 # smallest gathered block
+    (65536, 64),                               # largest below N/2 = 34,538
 ])
 def test_fused_score_topk_compiles(one_chip, compiled_kernels,
                                    n_docs, batch):
@@ -83,6 +85,26 @@ def test_fused_score_topk_compiles(one_chip, compiled_kernels,
         k=FULL.top_k, alpha=FULL.alpha, beta=FULL.beta,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
+    _fits_hbm(compiled)
+
+
+@pytest.mark.parametrize("rows", [1024, 65536])
+def test_candidate_gather_compiles(one_chip, rows):
+    """The IVF candidate gather from the block-aligned operands of one
+    chip's MS MARCO share (69,077 passages, aligned to 69,120 rows)."""
+    from repro.index.ivf import _gather_rows
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _gather_rows.lower(
+        s((69120, FULL.dim), jnp.float32),
+        s((69120, FULL.sig_words), jnp.int32),
+        s((rows,), jnp.int32),
+    ).compile()
+    # the two gathered blocks, and a tuple header
+    out = compiled.memory_analysis().output_size_in_bytes
+    assert 0 <= out - rows * (FULL.dim + FULL.sig_words) * 4 <= 4096
     _fits_hbm(compiled)
 
 
